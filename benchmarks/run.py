@@ -33,6 +33,9 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         bench_ablation,
         bench_accuracy,

@@ -834,6 +834,14 @@ def execute_packed(
 # Tensor-parallel execution (explicit shard_map path)
 # ---------------------------------------------------------------------------
 
+def needs_manual_spmd(spec: CiMExecSpec) -> bool:
+    """Whether ``spec`` runs a Pallas kernel. On a TPU a Pallas kernel
+    is a Mosaic custom call that XLA's SPMD partitioner refuses to
+    split, so under a sharded mesh it must run inside a shard_map
+    (:func:`execute_tp`)."""
+    return spec.resolve().backend in ("pallas", "pallas_stream")
+
+
 def execute_tp(
     spec: CiMExecSpec,
     x_t: jax.Array,
@@ -843,11 +851,13 @@ def execute_tp(
     axis_name: str = "model",
     compressed: bool = False,
     key: Optional[jax.Array] = None,
+    split: str = "row",
 ) -> jax.Array:
-    """Row-parallel ternary MAC over a mesh axis (explicit manual SPMD).
+    """Tensor-parallel ternary MAC over a mesh axis (explicit manual SPMD).
 
-    The contraction dim K is split over ``axis_name``: each device runs
-    the registered kernel on its K-shard and the partial sums all-reduce
+    ``split="row"`` (the default) splits the contraction dim K over
+    ``axis_name``: each device runs the registered kernel on its
+    K-shard and the partial sums all-reduce
     through :func:`repro.dist.collectives.tp_allreduce`. K is padded so
     every shard holds *whole* ``spec.block`` blocks — the per-block ADC
     clamp then never straddles a device boundary, the per-shard partials
@@ -868,14 +878,23 @@ def execute_tp(
     opt-in trade: 4x less collective traffic for quantization-level
     error — the exact path is the default.
 
+    ``split="col"`` splits the output dim N instead (column-parallel
+    weights): x is replicated, each device computes its own output
+    columns, no collective. When N does not divide the axis the weight
+    is replicated (``param_specs`` leaves it unsharded too) and every
+    device computes the whole product. ``compressed`` applies to the
+    row split only.
+
     This is the *explicit* TP entry point (shard_map — the collective is
     named in the program). Serving under plain sharded params/caches uses
-    the implicit GSPMD path instead and never needs this function; the
-    engine routes through it only for ``compress_tp=True`` (the
-    partitioner cannot be told to compress its own all-reduces).
-    Inference-only: no custom VJP is defined over the shard_map.
+    the implicit GSPMD path for jnp backends; the engine routes through
+    this function for ``compress_tp=True`` (the partitioner cannot be
+    told to compress its own all-reduces) and for Pallas backends, whose
+    Mosaic kernels the partitioner cannot split at all
+    (:func:`needs_manual_spmd`). Inference-only: no custom VJP is
+    defined over the shard_map.
     """
-    from repro.dist.collectives import shard_map, tp_allreduce
+    from repro.dist.collectives import tp_allreduce
 
     spec = spec.resolve()
     if spec.packing != "none":
@@ -890,10 +909,32 @@ def execute_tp(
             "execute_tp is the serving TP path; drive the sensing-error "
             "channel through execute/execute_packed (error_prob=0 here)"
         )
+    if split not in ("row", "col"):
+        raise ValueError(f"unknown split {split!r} (row | col)")
     entry = get_backend(spec)
     tp = int(mesh.shape[axis_name])
     lead, k, n = x_t.shape[:-1], x_t.shape[-1], w_t.shape[-1]
     x2 = x_t.reshape((-1, k))
+    from jax.sharding import PartitionSpec as _P
+
+    if split == "col":
+        n_axis = axis_name if n % tp == 0 else None
+        xc = _pad_axis(x2, spec.block, 1)
+        wc = _pad_axis(w_t, spec.block, 0)
+        tiles = tiles_for(spec, xc.shape[0], xc.shape[1],
+                          n // tp if n_axis else n)
+
+        def local_col(xs, ws):
+            if entry.tiles is None:
+                return entry.fn(xs, ws, spec)
+            return entry.fn(xs, ws, spec, tiles)
+
+        f = jax.shard_map(
+            local_col, mesh=mesh,
+            in_specs=(_P(), _P(None, n_axis)), out_specs=_P(None, n_axis),
+            check_vma=False,  # pallas_call has no replication rule
+        )
+        return f(xc, wc).reshape(lead + (n,)).astype(x_t.dtype)
     # whole blocks per shard: pad K to (block granularity) * tp
     mult = spec.block * tp
     x2 = _pad_axis(x2, mult, 1)
@@ -916,12 +957,11 @@ def execute_tp(
             part = entry.fn(xs, ws, spec, tiles)
         return tp_allreduce(part, axis_name, key=ks[0], compressed=compressed)
 
-    from jax.sharding import PartitionSpec as _P
-
-    f = shard_map(
+    f = jax.shard_map(
         local, mesh=mesh,
         in_specs=(_P(None, axis_name), _P(axis_name, None), _P(axis_name)),
         out_specs=_P(),
+        check_vma=False,  # pallas_call has no replication rule
     )
     return f(x2, wp, keys).reshape(lead + (n,)).astype(x_t.dtype)
 
@@ -952,7 +992,6 @@ def execute_packed_tp(
     :class:`repro.core.ternary.PackedPlanes`; its *padded* N must divide
     the mesh axis.
     """
-    from repro.dist.collectives import shard_map
     from jax.sharding import PartitionSpec as _P
 
     spec = spec.resolve()
@@ -995,11 +1034,11 @@ def execute_packed_tp(
         def local(xs, wl):
             return _packed_stream_forward(spec, tiles, xs, wl, wl.shape[-1])
 
-        f = shard_map(
+        f = jax.shard_map(
             local, mesh=mesh,
             in_specs=(_P(), _P(None, axis_name)),
             out_specs=_P(None, axis_name),
-            check_rep=False,  # pallas_call has no replication rule
+            check_vma=False,  # pallas_call has no replication rule
         )
         out = f(x2, w_int)
     else:
@@ -1010,11 +1049,11 @@ def execute_packed_tp(
         def local(xs, wp, wn):
             return _packed_forward(spec, tiles, xs, wp, wn, wp.shape[-1])
 
-        f = shard_map(
+        f = jax.shard_map(
             local, mesh=mesh,
             in_specs=(_P(), _P(None, axis_name), _P(None, axis_name)),
             out_specs=_P(None, axis_name),
-            check_rep=False,  # pallas_call has no replication rule
+            check_vma=False,  # pallas_call has no replication rule
         )
         out = f(x2, w_pos, w_neg)
     return out[:, :planes.n].reshape(lead + (planes.n,)).astype(x_t.dtype)

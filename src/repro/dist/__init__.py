@@ -10,6 +10,7 @@ from repro.dist.sharding import (  # noqa: F401
     cache_specs,
     disable_activation_sharding,
     enable_activation_sharding,
+    init_sharded,
     mesh_axis_sizes,
     model_axis_size,
     named_shardings,
@@ -19,5 +20,4 @@ from repro.dist.sharding import (  # noqa: F401
     shard_act,
     tp_mesh,
     tree_paths,
-    use_mesh,
 )

@@ -14,11 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.6 promotes shard_map out of experimental
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:
-    from jax.experimental.shard_map import shard_map
-
 
 def compressed_psum_int8(x: jax.Array, key: jax.Array, axis_name: str) -> jax.Array:
     """Int8-compressed psum over ``axis_name`` (call inside shard_map).
@@ -80,7 +75,7 @@ def mean_grads_int8(
         s = compressed_psum_int8(g, k[0], axis_name)
         return s / n
 
-    f = shard_map(
+    f = jax.shard_map(
         local, mesh=mesh, in_specs=(P(axis_name), P(axis_name)), out_specs=P()
     )
     return f(grads, keys)

@@ -69,23 +69,6 @@ def tree_paths(tree: PyTree) -> List[Tuple[str, Any]]:
 
 
 # ---------------------------------------------------------------------------
-# Mesh-context compatibility
-# ---------------------------------------------------------------------------
-
-
-def use_mesh(mesh):
-    """Version-portable mesh context: ``jax.set_mesh`` on new jax, the
-    ``Mesh`` object's own context manager on older releases. Usage:
-
-        with use_mesh(mesh):
-            jax.jit(f, in_shardings=...).lower(...)
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-# ---------------------------------------------------------------------------
 # Parameter specs
 # ---------------------------------------------------------------------------
 
@@ -191,6 +174,18 @@ def param_specs(
         return P(*spec)
 
     return jax.tree_util.tree_map_with_path(f, params)
+
+
+def init_sharded(init_fn, key: jax.Array, mesh) -> PyTree:
+    """Run ``init_fn(key)`` with every output leaf created directly
+    under its :func:`param_specs` sharding on ``mesh`` (jit with
+    ``out_shardings``): each device materializes only its own shard, so
+    a model too large for one device is never built whole on the first
+    one."""
+    shapes = jax.eval_shape(init_fn, key)
+    shardings = named_shardings(
+        mesh, param_specs(shapes, axis_sizes=mesh_axis_sizes(mesh)))
+    return jax.jit(init_fn, out_shardings=shardings)(key)
 
 
 # ---------------------------------------------------------------------------

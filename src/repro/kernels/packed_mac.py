@@ -22,13 +22,19 @@ Two variants share the format (DESIGN.md §9):
     int32 from int8 operands. Bit-identical to the prefill kernel
     (integer event counts are exact in both f32 and int32).
 
-VMEM budget per grid step, default (bm, bk, bn) = (128, 256, 128):
+Both unpack the planes through int32 (Mosaic casts uint8 only to other
+integers) and run the per-16-row MAC of
+:func:`repro.kernels.ternary_mac.block_event_mac`.
+
+VMEM budget per grid step, default (bm, bk, bn) = (128, 256, 128), with
+kb = bk/16 = 16 stacked copies of the x tile:
   x: 128*256*2 = 64 KiB; packed planes: 2 * (256/8)*128 = 8 KiB;
-  unpacked w: 256*128*2 = 64 KiB; out: 64 KiB; intermediates
-  2*(256/16)*128*128*4 = 2 MiB  -> ~2.2 MiB, fine for double buffering.
-Decode variant, default (bk, bn) = (256, 128) at M <= 8: the x tile is
-8*256*1 = 2 KiB int8 and the intermediates 2*(256/16)*8*128*4 = 128 KiB
-— the grid-step footprint shrinks ~16x with the M extent.
+  unpacked w: 256*128*2 = 64 KiB; out: 64 KiB; stacked x
+  16*128*256*(4+2) = 3 MiB (f32 masking + bf16 operand); partials
+  2*16*128*128*4 = 2 MiB -> ~5.2 MiB.
+Decode variant, default (bk, bn) = (256, 128) at M <= 8: the stacked x
+is 16*8*256*(4+1) = 160 KiB and the partials 2*16*8*128*4 = 128 KiB —
+the grid-step footprint shrinks ~16x with the M extent.
 """
 from __future__ import annotations
 
@@ -39,24 +45,26 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from repro.kernels.ternary_mac import block_event_mac
 
 DEFAULT_BLOCK = 16
 DEFAULT_ADC_MAX = 8
 
 
-def _unpack_plane_bits(plane: jax.Array, dtype) -> jax.Array:
-    """(bk/8, bn) uint8 -> (bk, bn) {0,1} bits in ``dtype``, K-major."""
+def _unpack_bits(plane: jax.Array) -> jax.Array:
+    """(kp, bn) uint8 -> (kp, 8, bn) {0,1} int32: bit ``b`` of byte-row
+    ``r`` is K index ``8r + b``. The bytes widen to int32 before any
+    shift (Mosaic casts uint8 only to other integers)."""
     kp, bn = plane.shape
-    shifts = jax.lax.broadcasted_iota(jnp.uint8, (kp, 8, bn), 1)
-    bits = (plane[:, None, :] >> shifts) & jnp.uint8(1)
-    return bits.reshape(kp * 8, bn).astype(dtype)
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (kp, 8, bn), 1)
+    return (plane.astype(jnp.int32)[:, None, :] >> shifts) & 1
 
 
-def _unpack_plane(plane: jax.Array) -> jax.Array:
-    """(bk/8, bn) uint8 -> (bk, bn) {0,1} float32 bits, K-major order."""
-    return _unpack_plane_bits(plane, jnp.float32)
+def _unpack_ternary(w_pos: jax.Array, w_neg: jax.Array, dtype) -> jax.Array:
+    """Two (bk/8, bn) uint8 planes -> the (bk, bn) ternary tile, K-major."""
+    kp, bn = w_pos.shape
+    w = _unpack_bits(w_pos) - _unpack_bits(w_neg)
+    return w.reshape(kp * 8, bn).astype(dtype)
 
 
 def _packed_kernel(x_ref, wp_ref, wn_ref, o_ref, *, sub, adc_max, cim):
@@ -64,27 +72,16 @@ def _packed_kernel(x_ref, wp_ref, wn_ref, o_ref, *, sub, adc_max, cim):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...].astype(jnp.float32)  # (bm, bk)
-    w = _unpack_plane(wp_ref[...]) - _unpack_plane(wn_ref[...])  # (bk, bn)
-    bm, bk = x.shape
-    bn = w.shape[-1]
+    x = x_ref[...]  # (bm, bk) bf16 ternary values
+    w = _unpack_ternary(wp_ref[...], wn_ref[...], x.dtype)  # (bk, bn)
     if not cim:
         o_ref[...] += jax.lax.dot_general(
             x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
         return
-    kb = bk // sub
-    xb = x.reshape(bm, kb, sub).swapaxes(0, 1)
-    wb = w.reshape(kb, sub, bn)
-    dims = (((2,), (1,)), ((0,), (0,)))
-    p = jax.lax.dot_general(xb, wb, dims, preferred_element_type=jnp.float32)
-    m = jax.lax.dot_general(
-        jnp.abs(xb), jnp.abs(wb), dims, preferred_element_type=jnp.float32
+    o_ref[...] += block_event_mac(
+        x, w, sub=sub, adc_max=adc_max, acc_dtype=jnp.float32
     )
-    a = (m + p) * 0.5
-    b = (m - p) * 0.5
-    part = jnp.minimum(a, adc_max) - jnp.minimum(b, adc_max)
-    o_ref[...] += jnp.sum(part, axis=0)
 
 
 @functools.partial(
@@ -129,11 +126,21 @@ def packed_cim_matmul(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m_dim, n_dim), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(x, w_pos, w_neg)
+
+
+def _int_mac(x, w, *, sub, adc_max, cim):
+    """int8 (m, bk) x int8 (bk, bn) -> int32 (m, bn): the decode kernels'
+    exact or per-block ADC-clamped MAC."""
+    if not cim:
+        return jax.lax.dot_general(
+            x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32
+        )
+    return block_event_mac(x, w, sub=sub, adc_max=adc_max, acc_dtype=jnp.int32)
 
 
 def _packed_decode_kernel(x_ref, wp_ref, wn_ref, o_ref, *, sub, adc_max, cim):
@@ -141,32 +148,8 @@ def _packed_decode_kernel(x_ref, wp_ref, wn_ref, o_ref, *, sub, adc_max, cim):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...]  # (m, bk) int8 ternary values
-    w = _unpack_plane_bits(wp_ref[...], jnp.int8) - _unpack_plane_bits(
-        wn_ref[...], jnp.int8
-    )  # (bk, bn) int8
-    m, bk = x.shape
-    bn = w.shape[-1]
-    if not cim:
-        o_ref[...] += jax.lax.dot_general(
-            x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32
-        )
-        return
-    kb = bk // sub
-    xb = x.reshape(m, kb, sub).swapaxes(0, 1)
-    wb = w.reshape(kb, sub, bn)
-    dims = (((2,), (1,)), ((0,), (0,)))
-    p = jax.lax.dot_general(xb, wb, dims, preferred_element_type=jnp.int32)
-    mm = jax.lax.dot_general(
-        jnp.abs(xb), jnp.abs(wb), dims, preferred_element_type=jnp.int32
-    )
-    # a/b are the RBL1/RBL2 discharge-event counts: small non-negative
-    # integers bounded by `sub` (TiM-DNN's partial-sum range analysis),
-    # so the halving and the clamp stay exact integer arithmetic
-    a = (mm + p) // 2
-    b = (mm - p) // 2
-    part = jnp.minimum(a, adc_max) - jnp.minimum(b, adc_max)
-    o_ref[...] += jnp.sum(part, axis=0)
+    w = _unpack_ternary(wp_ref[...], wn_ref[...], jnp.int8)  # (bk, bn)
+    o_ref[...] += _int_mac(x_ref[...], w, sub=sub, adc_max=adc_max, cim=cim)
 
 
 @functools.partial(
@@ -217,7 +200,7 @@ def packed_cim_matmul_decode(
         ],
         out_specs=pl.BlockSpec((m_dim, bn), lambda j, k: (0, j)),
         out_shape=jax.ShapeDtypeStruct((m_dim, n_dim), jnp.int32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -236,8 +219,6 @@ def _packed_decode_stream_kernel(
     """
     j = pl.program_id(0)
     o_ref[...] = jnp.zeros_like(o_ref)
-    x = x_ref[...]  # (m, K) int8 ternary values, whole K extent in VMEM
-    m = x.shape[0]
     bn = o_ref.shape[-1]
     tk = bk // 4  # interleaved byte-rows per (k, j) tile: pos+neg
 
@@ -263,32 +244,12 @@ def _packed_decode_stream_kernel(
                 tile_dma(jax.lax.rem(i + nbuf - 1, nbuf), i + nbuf - 1).start()
 
             tile_dma(slot, i).wait()
-            tile = scratch[slot]  # (bk//4, bn) uint8, pos/neg interleaved
-            pair = tile.reshape(bk // 8, 2, bn)
-            w = _unpack_plane_bits(pair[:, 0, :], jnp.int8) - _unpack_plane_bits(
-                pair[:, 1, :], jnp.int8
-            )  # (bk, bn) int8
-            xc = jax.lax.dynamic_slice_in_dim(x, i * bk, bk, axis=1)
-            if not cim:
-                o_ref[...] += jax.lax.dot_general(
-                    xc, w, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.int32,
-                )
-                return carry
-            kb = bk // sub
-            xb = xc.reshape(m, kb, sub).swapaxes(0, 1)
-            wb = w.reshape(kb, sub, bn)
-            dims = (((2,), (1,)), ((0,), (0,)))
-            p = jax.lax.dot_general(
-                xb, wb, dims, preferred_element_type=jnp.int32
-            )
-            mm = jax.lax.dot_general(
-                jnp.abs(xb), jnp.abs(wb), dims, preferred_element_type=jnp.int32
-            )
-            a = (mm + p) // 2
-            b = (mm - p) // 2
-            part = jnp.minimum(a, adc_max) - jnp.minimum(b, adc_max)
-            o_ref[...] += jnp.sum(part, axis=0)
+            # (bk/4, bn) uint8 tile, pos/neg byte-rows interleaved: split
+            # the leading dim of the unpacked bits, never the sublanes
+            bits = _unpack_bits(scratch[slot]).reshape(bk // 8, 2, 8, bn)
+            w = (bits[:, 0] - bits[:, 1]).reshape(bk, bn).astype(jnp.int8)
+            xc = x_ref[:, pl.ds(pl.multiple_of(i * bk, bk), bk)]
+            o_ref[...] += _int_mac(xc, w, sub=sub, adc_max=adc_max, cim=cim)
             return carry
 
         jax.lax.fori_loop(0, nk, step, 0)
@@ -354,11 +315,11 @@ def packed_cim_matmul_decode_stream(
         grid=(n_dim // bn,),
         in_specs=[
             pl.BlockSpec((m_dim, k_dim), lambda j: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((m_dim, bn), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((m_dim, n_dim), jnp.int32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

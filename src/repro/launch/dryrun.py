@@ -157,7 +157,7 @@ def lower_cell(
             def step(state, batch):
                 return ts.train_step(state, batch, cfg, opt_cfg)
 
-            with shd.use_mesh(mesh):
+            with jax.set_mesh(mesh):
                 lowered = jax.jit(
                     step,
                     in_shardings=(state_sh, batch_sh),
@@ -175,7 +175,7 @@ def lower_cell(
             def step(params, batch):
                 return T.forward(params, batch, cfg)
 
-            with shd.use_mesh(mesh):
+            with jax.set_mesh(mesh):
                 lowered = jax.jit(
                     step, in_shardings=(params_sh, batch_sh)
                 ).lower(params_shapes, specs)
@@ -205,7 +205,7 @@ def lower_cell(
             if enc_in_specs:
                 args.append(specs["enc"])
                 in_sh.append(batch_sh["enc"])
-            with shd.use_mesh(mesh):
+            with jax.set_mesh(mesh):
                 lowered = jax.jit(
                     step,
                     in_shardings=tuple(in_sh),

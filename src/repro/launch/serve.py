@@ -39,11 +39,13 @@ from repro.launch._boot import force_host_devices_for_tp
 force_host_devices_for_tp(sys.argv)  # before the jax import below
 
 import argparse
+import functools
 import time
 
 import jax
 
 from repro.core.execution import CiMExecSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.models.registry import get_config
 from repro.quant.prepare import ternarize_params
@@ -57,6 +59,20 @@ def parse_exec_spec(text: str) -> CiMExecSpec:
         raise ValueError(f"bad exec spec {text!r} (at most 4 '/'-fields)")
     fields = ("formulation", "backend", "packing", "flavor")
     return CiMExecSpec(**dict(zip(fields, parts)))
+
+
+def init_params(cfg, tp: int, seed: int = 0):
+    """Random parameters from ``seed``; with ``tp > 1`` each leaf is
+    created already sharded over the first ``tp`` devices (the mesh the
+    engine, or replica 0 of the front door, serves on)."""
+    key = jax.random.PRNGKey(seed)
+    if tp <= 1:
+        return T.init_params(key, cfg)
+    from repro.dist.sharding import init_sharded
+    from repro.launch.mesh import make_tp_mesh
+
+    return init_sharded(
+        functools.partial(T.init_params, cfg=cfg), key, make_tp_mesh(tp))
 
 
 def main(argv=None):
@@ -121,8 +137,9 @@ def main(argv=None):
                          "cleanly, exit 0")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
-    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    params = init_params(cfg, args.tp)
     if args.pre_quantize:
         import dataclasses
 
@@ -163,8 +180,9 @@ def main(argv=None):
     dt = time.perf_counter() - t0
     toks = sum(len(r.generated) for r in reqs)
     stats = batcher.stats()
+    dev = jax.devices()[0]
     print(f"[serve] {len(reqs)} requests, {toks} tokens, {dt:.2f}s "
-          f"({toks / max(dt, 1e-9):.1f} tok/s functional-CPU), "
+          f"({toks / max(dt, 1e-9):.1f} tok/s on {dev.platform} {dev.device_kind}), "
           f"{stats['decode_steps']} decode steps, "
           f"{stats['host_syncs']} host syncs "
           f"({'looped' if args.loop_decode else 'fused'} decode"
@@ -182,11 +200,13 @@ def main(argv=None):
 # ---------------------------------------------------------------------------
 
 
-def build_frontdoor(args, cfg, params, exec_spec):
+def build_frontdoor(args, cfg, params, exec_spec, batchers=None):
     """(FrontDoor, profiler) for the parsed args: N replica batchers
-    (disjoint (1, tp) meshes when --tp > 1), one router, one tracker.
-    Shared with benchmarks/bench_traffic.py so the bench serves through
-    the identical stack."""
+    (disjoint (1, tp) meshes when --tp > 1 or the devices suffice for
+    one each), one router, one tracker. ``batchers`` puts the door in
+    front of engines already built (``args.profile`` must then be off).
+    Shared with benchmarks/bench_traffic.py and chip_smoke.py so they
+    serve through the identical stack."""
     from repro.serve.frontdoor import (
         EngineWorker,
         FrontDoor,
@@ -195,7 +215,9 @@ def build_frontdoor(args, cfg, params, exec_spec):
     )
 
     meshes = [None] * args.replicas
-    if args.tp > 1:
+    if args.tp > 1 or 1 < args.replicas <= len(jax.devices()):
+        # one disjoint device group per replica (a (1, 1) mesh each at
+        # tp=1); with fewer devices than tp=1 replicas they share one
         from repro.launch.mesh import make_replica_meshes
 
         meshes = make_replica_meshes(args.replicas, args.tp)
@@ -206,7 +228,7 @@ def build_frontdoor(args, cfg, params, exec_spec):
         # one trace file for every replica AND the frontdoor.request
         # events — the profiler appends per event, so streams interleave
         profiler = Profiler(args.profile)
-    batchers = [
+    batchers = batchers or [
         ContinuousBatcher(
             params, cfg, n_slots=args.slots, s_max=args.s_max,
             exec_spec=exec_spec, temperature=args.temperature,
@@ -228,7 +250,7 @@ def build_frontdoor(args, cfg, params, exec_spec):
     return FrontDoor(router, tracker, host=args.host, port=args.port), profiler
 
 
-async def _selftest_session(door) -> None:
+async def selftest_session(door) -> None:
     """The CI front-door smoke: one full streamed request, one
     cancelled mid-stream, /stats agrees, nothing left in flight."""
     from repro.serve.frontdoor.client import WSClient, http_json
@@ -265,7 +287,7 @@ async def _serve_http_async(args, cfg, params, exec_spec) -> int:
           "routes: /healthz /stats /v1/generate /v1/stream")
     try:
         if args.selftest:
-            await _selftest_session(door)
+            await selftest_session(door)
         else:
             stop = asyncio.Event()
             loop = asyncio.get_running_loop()

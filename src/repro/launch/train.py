@@ -16,6 +16,7 @@ import jax
 
 from repro.data.pipeline import DataConfig, TokenPipeline
 from repro.dist import sharding as shd
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_smoke_mesh
 from repro.models.registry import get_config
 from repro.optim.adamw import AdamWConfig
@@ -38,6 +39,7 @@ def main(argv=None):
                     choices=[None, "off", "ternary", "cim", "cim_fused"])
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.quant:
         import dataclasses

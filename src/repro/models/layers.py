@@ -113,7 +113,7 @@ class QuantConfig:
     # explicit shard_map path (execution.execute_tp) with the
     # int8-compressed collective — 4x less TP wire traffic for
     # quantization-level error. Needs dist.sharding.set_tp_mesh (the
-    # serving engine installs it for compress_tp=True); inference-only.
+    # serving engine installs its mesh); inference-only.
     tp_reduce: str = "none"      # none | int8
     # KV-cache storage precision (DESIGN.md §13). "bf16" stores the
     # cache full-precision (bit-identical to the pre-§13 engine, pinned
@@ -263,19 +263,27 @@ def dense(
         #                 tiles, so no block intermediates reach HBM)
         spec = qc.resolved_spec()
         mac = exec_mac
-        if qc.tp_reduce == "int8" and tp == "row":
-            from repro.core.execution import execute_tp
-            from repro.dist.sharding import tp_mesh
+        from repro.core.execution import execute_tp, needs_manual_spmd
+        from repro.dist.sharding import tp_mesh
 
-            mesh = tp_mesh()
-            if mesh is not None and "model" in mesh.axis_names \
-                    and spec.resolve().packing == "none":
+        mesh = tp_mesh()
+        if mesh is not None and "model" in mesh.axis_names \
+                and spec.resolve().packing == "none":
+            if qc.tp_reduce == "int8" and tp == "row":
                 # explicit row-parallel shard_map MAC: the per-layer TP
                 # partial-sum all-reduce moves int8 (inference-only);
                 # the caller's key (if any) seeds the rounding stream
                 def mac(spec, x_q, w_q, key=None):
                     return execute_tp(spec, x_q, w_q, mesh,
                                       compressed=True, key=key)
+            elif needs_manual_spmd(spec) and mesh.shape["model"] > 1:
+                # a Pallas kernel cannot be split by the SPMD
+                # partitioner: run it per shard, row- or column-parallel
+                # as the layer's weight is sharded (exact either way)
+                split = "row" if tp == "row" else "col"
+
+                def mac(spec, x_q, w_q, key=None):
+                    return execute_tp(spec, x_q, w_q, mesh, split=split)
 
         if spec.clamps:
             out = mac(spec, x_t.astype(jnp.float32), w_t.astype(jnp.float32),

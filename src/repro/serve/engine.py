@@ -272,7 +272,6 @@ class ContinuousBatcher:
     ):
         self.packed = None
         self.mesh = mesh
-        self._compress_tp = bool(compress_tp)
         # opt-in measured-time observability (DESIGN.md §11): `profile`
         # is a repro.profile.Profiler, or a path to stream JSON-lines
         # events to, or None (the default — the step builders then get
@@ -435,11 +434,13 @@ class ContinuousBatcher:
         under their cache_specs sharding so the donated-buffer layout is
         a fixpoint across steps (no per-step reshard, no recompiles).
 
-        For ``compress_tp`` the call is additionally scoped under THIS
+        Under a mesh the call is additionally scoped under THIS
         batcher's mesh via the dist.sharding TP-mesh switch — installed
         around the call (where tracing happens) and restored after, so
         two batchers on different meshes in one process never read each
-        other's mesh and nothing leaks once the batcher is done.
+        other's mesh and nothing leaks once the batcher is done. dense()
+        reads it to run ``compress_tp`` MACs and Pallas kernels (which
+        the SPMD partitioner cannot split) per shard.
 
         With a profiler installed and ``entry_point`` named, the built
         step is wrapped with wall-time capture (repro.profile.trace);
@@ -452,7 +453,7 @@ class ContinuousBatcher:
             tok_ns = NamedSharding(self.mesh, P())
             jitted = jax.jit(f, donate_argnums=donate,
                              out_shardings=(tok_ns, self._cache_ns))
-        if self._compress_tp:
+        if self.mesh is not None:
             inner = jitted
 
             def scoped(*args):

@@ -12,36 +12,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.dist.collectives import (
     compressed_psum_int8,
     mean_grads_int8,
-    shard_map,
     tp_allreduce,
 )
-
-try:  # minimal installs: unit tests run, property tests are skipped
-    from hypothesis import given, settings, strategies as st
-
-    HAVE_HYPOTHESIS = True
-except ImportError:  # pragma: no cover
-    HAVE_HYPOTHESIS = False
-
-
-def _property_sweep(f):
-    """Hypothesis sweep when available; otherwise the test keeps its
-    defaulted args and the skipif mark makes the skip VISIBLE in -rs
-    (the CI tp-tests job greps for silent TP-suite skips — a vanished
-    test would defeat it)."""
-    if not HAVE_HYPOTHESIS:
-        return f
-    return settings(max_examples=20, deadline=None)(given(
-        seed=st.integers(0, 2**16),
-        size=st.sampled_from([64, 256, 1000]),
-        scale=st.floats(1e-3, 1e3),
-        shards=st.sampled_from([2, 4, 8]),
-    )(f))
 
 
 def _data_mesh(tp_mesh, n=4):
@@ -110,10 +89,14 @@ def test_tp_allreduce_compressed_requires_key(tp_mesh):
         raise AssertionError("compressed tp_allreduce without key accepted")
 
 
-@pytest.mark.skipif(not HAVE_HYPOTHESIS, reason="hypothesis not installed")
-@_property_sweep
-def test_compressed_psum_error_bound_property(seed=0, size=64, scale=1.0,
-                                              shards=2):
+@settings(max_examples=20, deadline=None)
+@given(
+    seed=st.integers(0, 2**16),
+    size=st.sampled_from([64, 256, 1000]),
+    scale=st.floats(1e-3, 1e3),
+    shards=st.sampled_from([2, 4, 8]),
+)
+def test_compressed_psum_error_bound_property(seed, size, scale, shards):
     """Property (previously skipped for want of a real mesh): for any
     payload, |compressed_psum - exact_sum| <= shards * (amax / 127) *
     1.5 — every shard rounds within one int8 level of the shared

@@ -1,9 +1,11 @@
 """Sharding rules + HLO analysis unit tests (logical — no big meshes;
 the 512-device meshes are exercised only by launch/dryrun.py)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.dist import sharding as shd
 from repro.launch import hlo_analysis as ha
@@ -12,6 +14,25 @@ from repro.models.registry import get_config
 
 
 class TestParamSpecs:
+    def test_init_sharded_creates_leaves_under_param_specs(self, tp_mesh):
+        """Parameters come out of init_sharded already placed under
+        their param_specs on the mesh, with the same values as the
+        unsharded init (threefry is partitionable)."""
+        from repro.launch.mesh import make_tp_mesh
+
+        cfg = get_config("smollm-135m", smoke=True)
+        mesh = make_tp_mesh(2)
+        init = functools.partial(T.init_params, cfg=cfg)
+        params = shd.init_sharded(init, jax.random.PRNGKey(0), mesh)
+        specs = shd.param_specs(params, axis_sizes=shd.mesh_axis_sizes(mesh))
+        flat_s = jax.tree.leaves(specs, is_leaf=lambda s: isinstance(s, P))
+        flat_p = jax.tree.leaves(params)
+        assert any("model" in s for s in flat_s)
+        for leaf, spec in zip(flat_p, flat_s):
+            assert leaf.sharding == NamedSharding(mesh, spec), spec
+        for a, b in zip(flat_p, jax.tree.leaves(init(jax.random.PRNGKey(0)))):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
     def test_rules_cover_model(self):
         cfg = get_config("yi-34b", smoke=True)
         params = jax.eval_shape(lambda k: T.init_params(k, cfg), jax.random.PRNGKey(0))
@@ -76,7 +97,7 @@ class TestActivationSharding:
         mesh = jax.make_mesh((1, 1), ("data", "model"))
         shd.enable_activation_sharding(multi_pod=False, batch_divisor=16)
         try:
-            with shd.use_mesh(mesh):
+            with jax.set_mesh(mesh):
                 x = jnp.ones((1, 8, 16))  # batch 1 not divisible: no crash
                 y = shd.shard_act(x, "btd")
                 assert y.shape == x.shape

@@ -143,6 +143,19 @@ class TestTPTokenIdentity:
         b.run()
         assert [r.generated for r in reqs] == base
 
+    def test_pallas_tp_serving_token_identical(self, tp_mesh):
+        """A Pallas spec under a TP mesh: the SPMD partitioner cannot
+        split a Mosaic kernel, so dense() runs each MAC per shard
+        (execute_tp row/col) — tokens still equal the unsharded engine,
+        and the engine's mesh switch does not leak."""
+        cfg = _family_cfg("dense")
+        params = T.init_params(jax.random.PRNGKey(0), cfg)
+        spec = CiMExecSpec(formulation="blocked", backend="pallas")
+        base, base_stats = _serve(params, cfg, None, exec_spec=spec)
+        toks, stats = _serve(params, cfg, make_tp_mesh(2), exec_spec=spec)
+        assert toks == base and stats == base_stats
+        assert shd.tp_mesh() is None
+
     def test_compress_tp_serves_and_differs_in_wire_only(self, tp_mesh):
         """compress_tp=True (int8 TP all-reduce) completes the workload
         with the same serving discipline; tokens may differ from the
@@ -240,6 +253,41 @@ class TestShardedExecute:
                 np.testing.assert_array_equal(base, out,
                                               err_msg=f"{form} tp={tp}")
 
+    @pytest.mark.parametrize("backend", ["jnp", "pallas"])
+    @pytest.mark.parametrize("split", ["row", "col"])
+    def test_execute_tp_split_bit_equal(self, split, backend, tp_mesh):
+        """Both explicit splits equal execute() bit for bit, for the
+        jnp formulation and the Pallas kernel, at TP=2 and TP=4 — with
+        an N (30) that TP=4 does not divide (the column split then
+        computes the replicated weight whole on every device)."""
+        spec = CiMExecSpec(formulation="blocked", backend=backend)
+        for n in (32, 30):
+            x, w = _ternary_pair(n=n)
+            base = np.asarray(execute(spec, x, w))
+            for tp in (2, 4):
+                out = np.asarray(execute_tp(spec, x, w, make_tp_mesh(tp),
+                                            split=split))
+                np.testing.assert_array_equal(
+                    base, out, err_msg=f"{split} n={n} tp={tp}")
+
+    @pytest.mark.parametrize("backend,per_shard", [("pallas", True),
+                                                   ("jnp", False)])
+    def test_dense_runs_pallas_per_shard(self, backend, per_shard, tp_mesh):
+        """Under an installed TP mesh dense() wraps a Pallas MAC in a
+        shard_map and leaves jnp MACs to the implicit GSPMD path."""
+        x, w = _ternary_pair()
+        qc = QuantConfig(mode="cim", exec_spec=CiMExecSpec(
+            formulation="blocked", backend=backend))
+
+        def f(a, b):
+            shd.set_tp_mesh(make_tp_mesh(2))
+            try:
+                return dense(a, b, qc, tp="row")
+            finally:
+                shd.set_tp_mesh(None)
+
+        assert ("shard_map" in str(jax.make_jaxpr(f)(x, w))) == per_shard
+
     def test_execute_tp_rejects_packed_and_noisy(self, tp_mesh):
         x, w = _ternary_pair()
         mesh = make_tp_mesh(2)
@@ -249,6 +297,9 @@ class TestShardedExecute:
         with pytest.raises(ValueError, match="error"):
             execute_tp(CiMExecSpec(formulation="blocked", backend="jnp",
                                    error_prob=0.1), x, w, mesh)
+        with pytest.raises(ValueError, match="split"):
+            execute_tp(CiMExecSpec(formulation="blocked", backend="jnp"),
+                       x, w, mesh, split="diag")
 
     def test_execute_tp_compressed_error_bound(self, tp_mesh):
         """int8-compressed TP all-reduce: per-shard quantization error is
