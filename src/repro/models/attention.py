@@ -341,6 +341,7 @@ def _sdpa_chunked(q, k, v, chunk: int,
     return jnp.moveaxis(out, -2, 1).reshape(b, sq, h, dh).astype(q.dtype)
 
 
+@L.scoped("attn")
 def gqa_attention(
     params,
     x: jax.Array,
@@ -426,6 +427,7 @@ def init_mla(key, cfg: ArchConfig, dtype=jnp.float32):
     return p
 
 
+@L.scoped("attn")
 def mla_attention(
     params,
     x: jax.Array,

@@ -24,6 +24,7 @@ the TiM-DNN peripheral applies them.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -33,6 +34,20 @@ from repro.core import ternary as tern
 from repro.core.execution import CiMExecSpec, execute as exec_mac
 
 Param = jax.Array
+
+
+def scoped(name: str):
+    """Decorator: trace the function under ``jax.named_scope(name)``, so
+    every HLO op it emits carries ``name`` on its ``op_name`` path
+    (metadata only; repro.profile.trace.SCOPES lists the names). The
+    scope is looked up at call time."""
+    def deco(f):
+        @functools.wraps(f)
+        def inner(*args, **kwargs):
+            with jax.named_scope(name):
+                return f(*args, **kwargs)
+        return inner
+    return deco
 
 # --- einsum accumulation strategy -----------------------------------------
 # TPU MXU consumes bf16 operands with f32 accumulation natively
@@ -231,69 +246,79 @@ def dense(
     so that all-reduce moves an int8 payload; everything else keeps the
     implicit GSPMD collectives (exact).
     """
-    if qc.mode == "off":
-        out = x @ w.astype(x.dtype)
+    if qc.mode != "off":
+        return _cim_dense(x, w, qc, bias, key, tp)
+    out = x @ w.astype(x.dtype)
+    if bias is not None:
+        out = out + bias.astype(out.dtype)
+    return out
+
+
+@scoped("cim")
+def _cim_dense(x, w, qc: QuantConfig, bias, key, tp: str) -> jax.Array:
+    """The quantized path of :func:`dense`: weight (re-)derivation, the
+    activation quantizer, the execution shim and its kernel, the scales
+    and the bias, all under the ``cim`` scope."""
+    if qc.pre_quantized:
+        # weights were ternarized offline with the per-channel scale
+        # folded in (values in {-s_n, 0, +s_n}); recover (t, s) with a
+        # single max-reduce — the CiM event counts need pure {-1,0,1}
+        # operands, and this is one pass over w instead of the ~4 the
+        # STE threshold quantizer costs.
+        sw = jnp.max(jnp.abs(w), axis=tuple(range(w.ndim - 1)), keepdims=True)
+        w_t = w / jnp.maximum(sw, jnp.asarray(1e-12, w.dtype))
+        sw = jax.lax.stop_gradient(sw)
     else:
-        if qc.pre_quantized:
-            # weights were ternarized offline with the per-channel scale
-            # folded in (values in {-s_n, 0, +s_n}); recover (t, s) with a
-            # single max-reduce — the CiM event counts need pure {-1,0,1}
-            # operands, and this is one pass over w instead of the ~4 the
-            # STE threshold quantizer costs.
-            sw = jnp.max(jnp.abs(w), axis=tuple(range(w.ndim - 1)), keepdims=True)
-            w_t = w / jnp.maximum(sw, jnp.asarray(1e-12, w.dtype))
-            sw = jax.lax.stop_gradient(sw)
-        else:
-            w_t, sw = _ternarize_weight(w, qc.threshold_factor)
-        if qc.quantize_activations:
-            x_t, sx = _ternarize_act(x, qc.threshold_factor,
-                                     per_row=qc.act_scale == "per_row")
-        else:
-            x_t, sx = x, jnp.ones((), x.dtype)
-        # One dispatch point for every ternary MAC: the spec (derived from
-        # the mode, or an explicit qc.exec_spec) picks the registered
-        # kernel; the shim owns padding, dtype policy, and the STE VJP.
-        #   ternary    -> exact/jnp: operand-dtype dot (the TP partial-sum
-        #                 all-reduce then moves bf16, not f32 — §Perf A4)
-        #   cim        -> blocked/auto: faithful per-16-block ADC clamp
-        #                 (Pallas kernel on TPU, jnp formulation on CPU)
-        #   cim_fused  -> fused/jnp: the kernel's HLO cost structure for
-        #                 dry-run/roofline work (numerically exact; on TPU
-        #                 the clamp happens inside the kernel's VMEM
-        #                 tiles, so no block intermediates reach HBM)
-        spec = qc.resolved_spec()
-        mac = exec_mac
-        from repro.core.execution import execute_tp, needs_manual_spmd
-        from repro.dist.sharding import tp_mesh
+        w_t, sw = _ternarize_weight(w, qc.threshold_factor)
+    if qc.quantize_activations:
+        x_t, sx = _ternarize_act(x, qc.threshold_factor,
+                                 per_row=qc.act_scale == "per_row")
+    else:
+        x_t, sx = x, jnp.ones((), x.dtype)
+    # One dispatch point for every ternary MAC: the spec (derived from
+    # the mode, or an explicit qc.exec_spec) picks the registered
+    # kernel; the shim owns padding, dtype policy, and the STE VJP.
+    #   ternary    -> exact/jnp: operand-dtype dot (the TP partial-sum
+    #                 all-reduce then moves bf16, not f32 — §Perf A4)
+    #   cim        -> blocked/auto: faithful per-16-block ADC clamp
+    #                 (Pallas kernel on TPU, jnp formulation on CPU)
+    #   cim_fused  -> fused/jnp: the kernel's HLO cost structure for
+    #                 dry-run/roofline work (numerically exact; on TPU
+    #                 the clamp happens inside the kernel's VMEM
+    #                 tiles, so no block intermediates reach HBM)
+    spec = qc.resolved_spec()
+    mac = exec_mac
+    from repro.core.execution import execute_tp, needs_manual_spmd
+    from repro.dist.sharding import tp_mesh
 
-        mesh = tp_mesh()
-        if mesh is not None and "model" in mesh.axis_names \
-                and spec.resolve().packing == "none":
-            if qc.tp_reduce == "int8" and tp == "row":
-                # explicit row-parallel shard_map MAC: the per-layer TP
-                # partial-sum all-reduce moves int8 (inference-only);
-                # the caller's key (if any) seeds the rounding stream
-                def mac(spec, x_q, w_q, key=None):
-                    return execute_tp(spec, x_q, w_q, mesh,
-                                      compressed=True, key=key)
-            elif needs_manual_spmd(spec) and mesh.shape["model"] > 1:
-                # a Pallas kernel cannot be split by the SPMD
-                # partitioner: run it per shard, row- or column-parallel
-                # as the layer's weight is sharded (exact either way)
-                split = "row" if tp == "row" else "col"
+    mesh = tp_mesh()
+    if mesh is not None and "model" in mesh.axis_names \
+            and spec.resolve().packing == "none":
+        if qc.tp_reduce == "int8" and tp == "row":
+            # explicit row-parallel shard_map MAC: the per-layer TP
+            # partial-sum all-reduce moves int8 (inference-only);
+            # the caller's key (if any) seeds the rounding stream
+            def mac(spec, x_q, w_q, key=None):
+                return execute_tp(spec, x_q, w_q, mesh,
+                                  compressed=True, key=key)
+        elif needs_manual_spmd(spec) and mesh.shape["model"] > 1:
+            # a Pallas kernel cannot be split by the SPMD
+            # partitioner: run it per shard, row- or column-parallel
+            # as the layer's weight is sharded (exact either way)
+            split = "row" if tp == "row" else "col"
 
-                def mac(spec, x_q, w_q, key=None):
-                    return execute_tp(spec, x_q, w_q, mesh, split=split)
+            def mac(spec, x_q, w_q, key=None):
+                return execute_tp(spec, x_q, w_q, mesh, split=split)
 
-        if spec.clamps:
-            out = mac(spec, x_t.astype(jnp.float32), w_t.astype(jnp.float32),
-                      key=key)
-        else:
-            out = mac(spec, x_t.astype(x.dtype), w_t.astype(x.dtype),
-                      key=key)
-        # fold scales in the output dtype: an f32 round-trip here makes
-        # every backward cotangent (and its all-reduce) f32 (§Perf A5)
-        out = out.astype(x.dtype) * (sx * sw).astype(x.dtype)
+    if spec.clamps:
+        out = mac(spec, x_t.astype(jnp.float32), w_t.astype(jnp.float32),
+                  key=key)
+    else:
+        out = mac(spec, x_t.astype(x.dtype), w_t.astype(x.dtype),
+                  key=key)
+    # fold scales in the output dtype: an f32 round-trip here makes
+    # every backward cotangent (and its all-reduce) f32 (§Perf A5)
+    out = out.astype(x.dtype) * (sx * sw).astype(x.dtype)
     if bias is not None:
         out = out + bias.astype(out.dtype)
     return out

@@ -134,6 +134,7 @@ def _ssd_chunked(x, dt, A, B, C, D, chunk: int):
     return y, h_final
 
 
+@L.scoped("ssm")
 def mamba2_block(
     params,
     x: jax.Array,

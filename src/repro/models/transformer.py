@@ -415,30 +415,35 @@ def decode_step(
         x, token_slices = jax.lax.scan(body, x, (params["blocks"], stacks))
         if ssm_like:
             new_caches = _wrap_cache(cfg, token_slices)
-        elif idx.ndim == 0:
-            # token_slices leaves: (L, B, s, ...); write at seq pos `index`
-            written = tuple(
-                jax.lax.dynamic_update_slice(
-                    stack,
-                    ts.astype(stack.dtype),
-                    (0, 0, idx) + (0,) * (stack.ndim - 3),
-                )
-                for stack, ts in zip(stacks, token_slices)
-            )
-            new_caches = _wrap_cache(cfg, written)
         else:
-            # ragged decode: every row writes all layers at its own offset
-            written = tuple(
-                jax.vmap(
-                    lambda stack_r, ts_r, i: jax.lax.dynamic_update_slice(
-                        stack_r, ts_r, (0, i) + (0,) * (stack_r.ndim - 2)),
-                    in_axes=(1, 1, 0),
-                    out_axes=1,
-                )(stack, ts.astype(stack.dtype), idx)
-                for stack, ts in zip(stacks, token_slices)
-            )
-            new_caches = _wrap_cache(cfg, written)
+            new_caches = _wrap_cache(cfg, _write_kv(stacks, token_slices, idx))
     x = L.rms_norm(x, params["final_norm"])
     table = params["embed"].T if cfg.tie_embeddings else params["unembed"]
-    logits = L.dense(x, table, L.QuantConfig(mode="off"))
+    with jax.named_scope("unembed"):
+        logits = L.dense(x, table, L.QuantConfig(mode="off"))
     return logits, new_caches
+
+
+@L.scoped("attn")
+def _write_kv(stacks, token_slices, idx):
+    """Write every layer's new-token KV slices (L, B, s, ...) into the
+    stacked caches after the layer scan: at one sequence offset
+    (scalar ``idx``), or each row at its own (ragged decode)."""
+    if idx.ndim == 0:
+        return tuple(
+            jax.lax.dynamic_update_slice(
+                stack,
+                ts.astype(stack.dtype),
+                (0, 0, idx) + (0,) * (stack.ndim - 3),
+            )
+            for stack, ts in zip(stacks, token_slices)
+        )
+    return tuple(
+        jax.vmap(
+            lambda stack_r, ts_r, i: jax.lax.dynamic_update_slice(
+                stack_r, ts_r, (0, i) + (0,) * (stack_r.ndim - 2)),
+            in_axes=(1, 1, 0),
+            out_axes=1,
+        )(stack, ts.astype(stack.dtype), idx)
+        for stack, ts in zip(stacks, token_slices)
+    )

@@ -34,11 +34,18 @@ Event schema (JSON-lines; ``v`` is :data:`TRACE_SCHEMA_VERSION`)::
 ``dispatch_us`` is the host time to *enqueue* the work — their
 difference isolates what the profiler's own sync added to the step, so
 fused-step analyses can subtract it.
+
+Apart from these events, :func:`span` marks host work in JAX's own
+profiler trace (the engine's ``serve.*`` spans), and :data:`SCOPES`
+names the ``jax.named_scope`` regions of the fused programs, which
+:func:`hlo_op_names` and :func:`scope_of` read back from a compiled
+program's HLO text. Neither blocks the host or changes a program.
 """
 from __future__ import annotations
 
 import dataclasses
 import json
+import re
 import time
 from pathlib import Path
 from typing import Any, Callable, Dict, List, Mapping, Optional, Union
@@ -212,6 +219,52 @@ def set_profiler(p: Optional[Profiler]) -> Optional[Profiler]:
 def current_profiler() -> Optional[Profiler]:
     """The installed process-wide profiler, or None."""
     return _ACTIVE
+
+
+# ---------------------------------------------------------------------------
+# Spans and named scopes in the profiler's own trace
+# ---------------------------------------------------------------------------
+
+#: the ``jax.named_scope`` names the model and the engine give their
+#: parts of the fused programs; an HLO op belongs to the innermost one
+#: on its ``op_name`` path
+SCOPES = ("attn", "ssm", "cim", "unembed", "sample", "fill.merge")
+
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?(%\S+)\s+=\s")
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+
+
+def span(name: str, **args):
+    """A host span: ``jax.profiler.TraceAnnotation(name, **args)``, so it
+    lands in the profiler's trace on the same clock as the device's
+    operations, with ``args`` as the event's stats. With no profiler
+    session active it records nothing and costs what an inactive
+    annotation costs (about a microsecond)."""
+    return jax.profiler.TraceAnnotation(name, **args)
+
+
+def scope_of(op_name: str) -> Optional[str]:
+    """The innermost of :data:`SCOPES` on an HLO ``op_name`` path
+    (``jit(step)/while/body/attn/cim/dot_general`` -> ``cim``)."""
+    for part in reversed(op_name.split("/")):
+        if part in SCOPES:
+            return part
+    return None
+
+
+def hlo_op_names(hlo_text: str) -> Dict[str, str]:
+    """Map every instruction of a compiled program's HLO text, by its
+    full name (``%copy.7``), to the ``op_name`` of its metadata (``""``
+    where it has none): the path of named scopes and primitives that
+    emitted it, or the name of the program argument an inserted copy
+    relays out (``caches.k``). :func:`scope_of` reads its scope."""
+    out: Dict[str, str] = {}
+    for line in hlo_text.splitlines():
+        m = _INSTR.match(line)
+        if m:
+            op = _OP_NAME.search(line)
+            out[m.group(1)] = op.group(1) if op else ""
+    return out
 
 
 def backend_block() -> Dict[str, Any]:

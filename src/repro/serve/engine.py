@@ -22,6 +22,7 @@ import numpy as np
 from repro.configs.base import ArchConfig
 from repro.core.execution import CiMExecSpec
 from repro.models import transformer as T
+from repro.profile.trace import span
 
 PyTree = Any
 
@@ -117,7 +118,8 @@ def fused_decode_fn(cfg: ArchConfig, temperature: float = 0.0):
     def step(params, tokens, caches, positions, start, key):
         logits, caches = T.decode_step(
             params, tokens, caches, positions, cfg, start=start)
-        toks = sample(logits[:, -1:, :], key, temperature)[:, 0]
+        with jax.named_scope("sample"):
+            toks = sample(logits[:, -1:, :], key, temperature)[:, 0]
         return toks, caches
 
     return step
@@ -407,6 +409,14 @@ class ContinuousBatcher:
         self.decode_steps = 0
         self.host_syncs = 0
         self.prefill_batches = 0
+        # what the fused fills and decode steps computed, and how much of
+        # it served a request (stats(); fused path only)
+        self.fill_rows_new = 0
+        self.fill_rows_computed = 0
+        self.fill_tokens_prompt = 0
+        self.fill_tokens_computed = 0
+        self.decode_rows_active = 0
+        self.decode_rows_computed = 0
         self._step_idx = 0
         self._prefill_idx = 0
         if not fused and self.temperature != 0.0:
@@ -509,13 +519,15 @@ class ContinuousBatcher:
             logits, new = T.decode_step(
                 params, tokens, fresh, jnp.int32(0), cfg, start=start)
             # left-padding: the last column is every row's last real token
-            toks = self._sample_on_device(logits[:, -1, :], key)
+            with jax.named_scope("sample"):
+                toks = self._sample_on_device(logits[:, -1, :], key)
 
             def merge(old, nw):
                 m = fill_mask.reshape((1, n) + (1,) * (old.ndim - 2))
                 return jnp.where(m, nw.astype(old.dtype), old)
 
-            return toks, jax.tree.map(merge, caches, new)
+            with jax.named_scope("fill.merge"):
+                return toks, jax.tree.map(merge, caches, new)
 
         def meta(*_args):
             # _fill_slots_fused stages the batch description here right
@@ -527,83 +539,99 @@ class ContinuousBatcher:
                               shape_class="prefill", meta_fn=meta)
 
     def _fill_slots_fused(self):
-        newly = []
-        for s in range(self.n_slots):
-            if self.slot_req[s] is None and self.queue:
-                self.slot_req[s] = self.queue.pop(0)
-                newly.append(s)
-        if not newly:
+        free = [s for s in range(self.n_slots) if self.slot_req[s] is None]
+        admitted = self.queue[:len(free)]
+        if not admitted:
             return
-        max_len = max(len(self.slot_req[s].prompt) for s in newly)
+        max_len = max(len(r.prompt) for r in admitted)
         s_pad = _next_pow2(max_len)  # bucketed: bounds prefill recompiles
         if s_pad >= self.s_max:
             # don't let the bucket make a servable prompt unservable:
             # fall back to the exact length (one extra compile, worth it)
             s_pad = max_len
-        tokens = np.zeros((self.n_slots, s_pad), np.int32)
-        start = np.zeros((self.n_slots,), np.int32)
-        fill = np.zeros((self.n_slots,), bool)
-        for s in newly:
-            prompt = self.slot_req[s].prompt
-            pad = s_pad - len(prompt)
-            tokens[s, pad:] = prompt
-            start[s] = pad
-            fill[s] = True
-        # decode steps draw even fold_in streams, prefill batches odd ones
-        key = jax.random.fold_in(self._key, 2 * self._prefill_idx + 1)
-        self._prefill_idx += 1
-        if self.profiler is not None:
-            self._prefill_meta = {
-                "arch": self.cfg.name,
-                "prompts": [
-                    (self.slot_req[s].rid, len(self.slot_req[s].prompt),
-                     self.slot_req[s].max_new)
-                    for s in newly
-                ],
-                "s_pad": s_pad,
-                "filled": len(newly),
-            }
-        toks, self.caches = self._prefill(
-            self.params, self.caches, jnp.asarray(tokens), jnp.asarray(start),
-            jnp.asarray(fill), key)
-        # analysis: host-sync ok — the one documented fetch per fill batch
-        toks = np.asarray(toks)
-        self.host_syncs += 1
-        self.prefill_batches += 1
-        for s in newly:
-            req = self.slot_req[s]
-            req.generated.append(int(toks[s]))
-            self._last_tok[s] = toks[s]
-            self.slot_pos[s] = s_pad
-            self.slot_start[s] = start[s]
-            if len(req.generated) >= req.max_new:
-                req.done = True
-                self.slot_req[s] = None
+        # the host's part of a fill, span by span (repro.profile.trace):
+        # stage the inputs, dispatch the program, wait for its tokens,
+        # commit them to the requests
+        with span("serve.fill.stage", rows=len(admitted), s_pad=s_pad,
+                  rids=[r.rid for r in admitted]):
+            newly = free[:len(admitted)]
+            del self.queue[:len(admitted)]
+            tokens = np.zeros((self.n_slots, s_pad), np.int32)
+            start = np.zeros((self.n_slots,), np.int32)
+            fill = np.zeros((self.n_slots,), bool)
+            for s, req in zip(newly, admitted):
+                self.slot_req[s] = req
+                pad = s_pad - len(req.prompt)
+                tokens[s, pad:] = req.prompt
+                start[s] = pad
+                fill[s] = True
+            # decode steps draw even fold_in streams, prefill batches odd ones
+            key = jax.random.fold_in(self._key, 2 * self._prefill_idx + 1)
+            self._prefill_idx += 1
+            if self.profiler is not None:
+                self._prefill_meta = {
+                    "arch": self.cfg.name,
+                    "prompts": [(r.rid, len(r.prompt), r.max_new)
+                                for r in admitted],
+                    "s_pad": s_pad,
+                    "filled": len(newly),
+                }
+            inputs = (jnp.asarray(tokens), jnp.asarray(start), jnp.asarray(fill))
+        with span("serve.fill.dispatch"):
+            toks, self.caches = self._prefill(
+                self.params, self.caches, *inputs, key)
+        with span("serve.fill.fetch"):
+            # analysis: host-sync ok — the one documented fetch per fill batch
+            toks = np.asarray(toks)
+        with span("serve.fill.commit"):
+            self.host_syncs += 1
+            self.prefill_batches += 1
+            self.fill_rows_new += len(newly)
+            self.fill_rows_computed += self.n_slots
+            self.fill_tokens_prompt += sum(len(r.prompt) for r in admitted)
+            self.fill_tokens_computed += self.n_slots * s_pad
+            for s in newly:
+                req = self.slot_req[s]
+                req.generated.append(int(toks[s]))
+                self._last_tok[s] = toks[s]
+                self.slot_pos[s] = s_pad
+                self.slot_start[s] = start[s]
+                if len(req.generated) >= req.max_new:
+                    req.done = True
+                    self.slot_req[s] = None
 
     def _step_fused(self, active) -> int:
-        tokens = jnp.asarray(self._last_tok[:, None])
-        positions = jnp.asarray(self.slot_pos)
-        start = jnp.asarray(self.slot_start)
-        key = jax.random.fold_in(self._key, 2 * self._step_idx)
-        toks, self.caches = self._decode(
-            self.params, tokens, self.caches, positions, start, key)
+        with span("serve.decode.stage", active=len(active)):
+            tokens = jnp.asarray(self._last_tok[:, None])
+            positions = jnp.asarray(self.slot_pos)
+            start = jnp.asarray(self.slot_start)
+            key = jax.random.fold_in(self._key, 2 * self._step_idx)
+        with span("serve.decode.dispatch"):
+            toks, self.caches = self._decode(
+                self.params, tokens, self.caches, positions, start, key)
         self.decode_steps += 1
         self._step_idx += 1
-        # analysis: host-sync ok — the single documented fetch of this step
-        toks = np.asarray(toks)
-        self.host_syncs += 1
-        for s in active:
-            req = self.slot_req[s]
-            req.generated.append(int(toks[s]))
-            self._last_tok[s] = toks[s]
-            self.slot_pos[s] += 1
-            # capacity boundary: slot_pos is the NEXT cache write offset,
-            # so decoding may continue while slot_pos <= s_max - 1 (the
-            # last cache slot is usable); `>= s_max - 1` here wasted it
-            if len(req.generated) >= req.max_new or self.slot_pos[s] >= self.s_max:
-                req.done = True
-                req.truncated = len(req.generated) < req.max_new
-                self.slot_req[s] = None
+        with span("serve.decode.fetch"):
+            # analysis: host-sync ok — the single documented fetch of this step
+            toks = np.asarray(toks)
+        with span("serve.decode.commit"):
+            self.host_syncs += 1
+            self.decode_rows_active += len(active)
+            self.decode_rows_computed += self.n_slots
+            for s in active:
+                req = self.slot_req[s]
+                req.generated.append(int(toks[s]))
+                self._last_tok[s] = toks[s]
+                self.slot_pos[s] += 1
+                # capacity boundary: slot_pos is the NEXT cache write
+                # offset, so decoding may continue while slot_pos <=
+                # s_max - 1 (the last cache slot is usable); `>= s_max - 1`
+                # here wasted it
+                if (len(req.generated) >= req.max_new
+                        or self.slot_pos[s] >= self.s_max):
+                    req.done = True
+                    req.truncated = len(req.generated) < req.max_new
+                    self.slot_req[s] = None
         return len(active)
 
     # -- legacy per-slot-loop baseline (benchmarks/bench_serve.py) ----------
@@ -746,19 +774,27 @@ class ContinuousBatcher:
 
     def step(self) -> int:
         """One decode step over all active slots; returns #active."""
-        self._fill_slots()
-        active = [s for s in range(self.n_slots) if self.slot_req[s] is not None]
-        if not active:
-            return 0
-        if self.fused:
-            return self._step_fused(active)
-        return self._step_looped(active)
+        with span("serve.step"):
+            self._fill_slots()
+            active = [s for s in range(self.n_slots)
+                      if self.slot_req[s] is not None]
+            if not active:
+                return 0
+            if self.fused:
+                return self._step_fused(active)
+            return self._step_looped(active)
 
     def stats(self) -> Dict[str, int]:
         return {
             "decode_steps": self.decode_steps,
             "host_syncs": self.host_syncs,
             "prefill_batches": self.prefill_batches,
+            "fill_rows_new": self.fill_rows_new,
+            "fill_rows_computed": self.fill_rows_computed,
+            "fill_tokens_prompt": self.fill_tokens_prompt,
+            "fill_tokens_computed": self.fill_tokens_computed,
+            "decode_rows_active": self.decode_rows_active,
+            "decode_rows_computed": self.decode_rows_computed,
         }
 
     def run(self) -> None:
