@@ -190,6 +190,22 @@ def write_cache_rows(buf: jax.Array, new: jax.Array, index: jax.Array) -> jax.Ar
     return jax.vmap(row)(buf, new, index)
 
 
+def attend_rows(buf: jax.Array, new: jax.Array, index: jax.Array) -> jax.Array:
+    """The cache as attention reads it once ``new`` (B, s, ...) sits at
+    ``index`` in ``buf`` (B, S_max, ...); the caller writes the cache.
+
+    For one ragged decode token (``index`` of shape (B,), s == 1) this is
+    a select, ``new`` where a position is its row's own index and
+    ``buf`` elsewhere: it fuses into the score and value contractions,
+    which then read the cache once where it lies, and nothing of cache
+    size is written. Otherwise it is :func:`write_cache_rows`."""
+    if jnp.ndim(index) == 0 or new.shape[1] != 1:
+        return write_cache_rows(buf, new, index)
+    hit = jnp.arange(buf.shape[1], dtype=jnp.int32)[None, :] == index[:, None]
+    hit = hit.reshape(hit.shape + (1,) * (buf.ndim - 2))
+    return jnp.where(hit, new.astype(buf.dtype), buf)
+
+
 def _index_vector(index, b: int) -> jax.Array:
     """Normalize a scalar-or-(B,) cache index to a (B,) int32 vector."""
     return jnp.broadcast_to(jnp.asarray(index, jnp.int32), (b,))
@@ -351,10 +367,11 @@ def gqa_attention(
     cache_index: Optional[jax.Array] = None,
     start: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Optional[KVCache]]:
-    """x: (B, S, D). With a cache: decode/prefill-append mode — new KV
-    written at ``cache_index`` (scalar, or (B,) for ragged decode where
-    every row writes at its own position); attention runs against the
-    whole cache. ``start`` marks each row's first valid cache slot
+    """x: (B, S, D). With a cache: decode/prefill-append mode — attention
+    runs against the whole cache with the new KV at ``cache_index``
+    (scalar, or (B,) for ragged decode where every row sits at its own
+    position; :func:`attend_rows`), and the new KV is returned for the
+    caller to write. ``start`` marks each row's first valid cache slot
     (left-padding dead zone — see DESIGN.md §6). A :class:`QuantKVCache`
     quantizes the new tokens on write and attends over codes + scales
     (DESIGN.md §13); the :class:`KVCache` path is untouched — bf16
@@ -378,10 +395,10 @@ def gqa_attention(
         cd = "ternary" if cache.k.dtype == jnp.uint8 else "int8"
         k_q, k_s = quantize_kv(k, cd)
         v_q, v_s = quantize_kv(v, cd)
-        k_all = write_cache_rows(cache.k, k_q, cache_index)
-        v_all = write_cache_rows(cache.v, v_q, cache_index)
-        ks_all = write_cache_rows(cache.k_scale, k_s, cache_index)
-        vs_all = write_cache_rows(cache.v_scale, v_s, cache_index)
+        k_all = attend_rows(cache.k, k_q, cache_index)
+        v_all = attend_rows(cache.v, v_q, cache_index)
+        ks_all = attend_rows(cache.k_scale, k_s, cache_index)
+        vs_all = attend_rows(cache.v_scale, v_s, cache_index)
         new_cache = QuantKVCache(k_q, v_q, k_s, v_s)
         length = _index_vector(cache_index, b) + s
         out = _sdpa(
@@ -389,8 +406,8 @@ def gqa_attention(
             start=start, k_scale=ks_all, v_scale=vs_all,
         )
     else:
-        k_all = write_cache_rows(cache.k, k, cache_index)
-        v_all = write_cache_rows(cache.v, v, cache_index)
+        k_all = attend_rows(cache.k, k, cache_index)
+        v_all = attend_rows(cache.v, v, cache_index)
         # Return only the new-token KV: the caller owns the stacked cache
         # and writes just this slice (avoids restacking the full per-layer
         # cache through the layer scan — decode HBM traffic stays
@@ -457,17 +474,17 @@ def mla_attention(
         cd = "ternary" if cache.ckv.dtype == jnp.uint8 else "int8"
         ckv_q, ckv_s = quantize_kv(ckv, cd)
         kr_q, kr_s = quantize_kv(k_rope, cd)
-        ckv_all = write_cache_rows(cache.ckv, ckv_q, cache_index)
-        krope_all = write_cache_rows(cache.k_rope, kr_q, cache_index)
-        ckv_scale = write_cache_rows(cache.ckv_scale, ckv_s, cache_index)
-        krope_scale = write_cache_rows(cache.krope_scale, kr_s, cache_index)
+        ckv_all = attend_rows(cache.ckv, ckv_q, cache_index)
+        krope_all = attend_rows(cache.k_rope, kr_q, cache_index)
+        ckv_scale = attend_rows(cache.ckv_scale, ckv_s, cache_index)
+        krope_scale = attend_rows(cache.krope_scale, kr_s, cache_index)
         new_cache = QuantMLACache(ckv_q, kr_q, ckv_s, kr_s)
         offset = cache_index
         sk = ckv_all.shape[1]
         length = _index_vector(cache_index, b) + s
     elif cache is not None:
-        ckv_all = write_cache_rows(cache.ckv, ckv, cache_index)
-        krope_all = write_cache_rows(cache.k_rope, k_rope, cache_index)
+        ckv_all = attend_rows(cache.ckv, ckv, cache_index)
+        krope_all = attend_rows(cache.k_rope, k_rope, cache_index)
         # new-token slices only; caller writes them into the stacked cache
         new_cache = MLACache(ckv.astype(cache.ckv.dtype), k_rope.astype(cache.k_rope.dtype))
         offset = cache_index

@@ -383,9 +383,10 @@ def decode_step(
     coordinates ``index - start`` so each row's first real token is
     position 0 regardless of padding (DESIGN.md §6).
 
-    The stacked caches ride in the scan *carry* and receive in-place
-    token-slice writes (attention) / state writes (SSM) at the current
-    layer — never restacked through scan outputs.
+    The layer scan reads each layer's cache as an ``xs`` slice and emits
+    only what is new: the token's K/V slices (attention; written into
+    the stacked caches after the scan, in place) or the layer's new
+    state (SSM; the scan's outputs are the new caches).
     """
     x = L.embed(tokens, params["embed"]).astype(_dtype(cfg))
     b, s = x.shape[:2]
@@ -402,10 +403,12 @@ def decode_step(
         ssm_like = cfg.family == "ssm"
 
         # Scan reads each layer's cache as an xs slice (no carry mutation)
-        # and emits only the new-token slice / new state as ys; one
-        # vectorized dynamic-update-slice after the scan writes all layers
-        # at once. XLA keeps both the xs reads and the final DUS in place,
-        # so decode HBM traffic is O(cache read + token write).
+        # and emits only the new-token slice / new state as ys. Ragged
+        # decode attends through a select view of the slice (the new token
+        # at its row's own position) that fuses into the contractions, so
+        # nothing of cache size is written inside the scan; after it,
+        # _write_kv puts each slot's token slice into the donated caches
+        # in place. Decode HBM traffic is one cache read plus the tokens.
         def body(y, xs):
             p, c = xs
             c = _wrap_cache(cfg, c)
@@ -424,11 +427,17 @@ def decode_step(
     return logits, new_caches
 
 
-@L.scoped("attn")
+@L.scoped("kv.write")
 def _write_kv(stacks, token_slices, idx):
     """Write every layer's new-token KV slices (L, B, s, ...) into the
     stacked caches after the layer scan: at one sequence offset
-    (scalar ``idx``), or each row at its own (ragged decode)."""
+    (scalar ``idx``), or each row at its own (ragged decode).
+
+    The ragged write is a loop over slots, each an in-place
+    dynamic_update_slice of that slot's (L, 1, s, ...) slice at
+    ``(0, b, idx[b])``: the donated caches keep their own layout. (A
+    vmapped or ``.at[]`` write lowers to a scatter, for which the TPU
+    compiler relays out both whole caches and back every step.)"""
     if idx.ndim == 0:
         return tuple(
             jax.lax.dynamic_update_slice(
@@ -438,12 +447,15 @@ def _write_kv(stacks, token_slices, idx):
             )
             for stack, ts in zip(stacks, token_slices)
         )
-    return tuple(
-        jax.vmap(
-            lambda stack_r, ts_r, i: jax.lax.dynamic_update_slice(
-                stack_r, ts_r, (0, i) + (0,) * (stack_r.ndim - 2)),
-            in_axes=(1, 1, 0),
-            out_axes=1,
-        )(stack, ts.astype(stack.dtype), idx)
-        for stack, ts in zip(stacks, token_slices)
-    )
+
+    def slot(b, stacks):
+        return tuple(
+            jax.lax.dynamic_update_slice(
+                stack,
+                jax.lax.dynamic_slice_in_dim(ts, b, 1, axis=1).astype(stack.dtype),
+                (0, b, idx[b]) + (0,) * (stack.ndim - 3),
+            )
+            for stack, ts in zip(stacks, token_slices)
+        )
+
+    return jax.lax.fori_loop(0, idx.shape[0], slot, tuple(stacks))

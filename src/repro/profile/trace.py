@@ -228,7 +228,7 @@ def current_profiler() -> Optional[Profiler]:
 #: the ``jax.named_scope`` names the model and the engine give their
 #: parts of the fused programs; an HLO op belongs to the innermost one
 #: on its ``op_name`` path
-SCOPES = ("attn", "ssm", "cim", "unembed", "sample", "fill.merge")
+SCOPES = ("attn", "ssm", "cim", "unembed", "sample", "fill.merge", "kv.write")
 
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?(%\S+)\s+=\s")
 _OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')

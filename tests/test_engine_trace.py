@@ -195,7 +195,8 @@ def _tagged(op_names, tag, i):
 def test_every_cim_call_and_attention_op_is_scoped(monkeypatch):
     """In the smoke decode program every CiM MAC (the execution shim
     call inside dense()) is in a ``cim`` scope, and every op of the
-    attention core and of the cache reads and writes is in ``attn``."""
+    attention core and of the cache reads and writes is in ``attn``, and
+    the KV write after the layer scan in ``kv.write``."""
     macs = _probe(monkeypatch, L, "exec_mac", "mac_probe")
     sdpa = _probe(monkeypatch, attn_lib, "_sdpa", "sdpa_probe")
     rows = _probe(monkeypatch, attn_lib, "write_cache_rows", "rows_probe")
@@ -215,7 +216,7 @@ def test_every_cim_call_and_attention_op_is_scoped(monkeypatch):
             assert {scope_of(v) for v in ops} == {want}, (tag, i)
     # the cache writes after the layer scan, the unembedding and sampling
     scopes = {scope_of(v) for v in names.values()}
-    assert {"attn", "cim", "unembed", "sample"} <= scopes
+    assert {"attn", "kv.write", "cim", "unembed", "sample"} <= scopes
     assert "fill.merge" in {scope_of(v) for v in hlo_op_names(fill).values()}
     monkeypatch.undo()
     jax.clear_caches()
@@ -241,7 +242,8 @@ def test_scope_of_takes_the_innermost():
     assert scope_of("jit(pf)/fill.merge/select_n") == "fill.merge"
     assert scope_of("jit(step)/while/body/dynamic_update_slice") is None
     assert scope_of("caches.k") is None
-    assert set(SCOPES) == {"attn", "ssm", "cim", "unembed", "sample", "fill.merge"}
+    assert set(SCOPES) == {"attn", "ssm", "cim", "unembed", "sample", "fill.merge",
+                           "kv.write"}
 
 
 def test_hlo_op_names_reads_full_instruction_names():

@@ -13,6 +13,7 @@ import time: only one process at a time may load the TPU library, and
 under pytest-xdist every worker imports this file.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +26,10 @@ from repro.kernels.packed_mac import (
     packed_cim_matmul_decode_stream,
 )
 from repro.kernels.ternary_mac import ternary_cim_matmul, ternary_exact_matmul
+from repro.models import transformer as T
+from repro.models.layers import QuantConfig
+from repro.models.registry import get_config
+from repro.serve.engine import fused_decode_fn
 
 D_MODEL, D_FF, QKV = 576, 1536, 960
 
@@ -120,3 +125,49 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     shapes = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in args]
     compiled = jax.jit(fn).lower(*shapes).compile()
     assert "tpu_custom_call" in compiled.as_text(), case
+
+
+def _layer_layout(layout):
+    """The layout of one layer's slice (axis 0 dropped) of a stacked
+    array laid out as ``layout`` (``"2,4,3,1,0:T(8,128)(2,1)"``)."""
+    order, _, tiles = layout.partition(":")
+    dims = [int(d) for d in order.split(",")]
+    kept = ",".join(str(d - 1) for d in dims if d != 0)
+    return f"{kept}:{tiles}" if tiles else kept
+
+
+def test_fused_decode_writes_the_kv_cache_in_place(one_chip):
+    """smollm-135m's fused decode step at 64 slots and s_max 2048 (bf16
+    cache, donated) never relays out its KV cache: every instruction
+    whose result is a whole stacked cache or one layer's slice of it has
+    the argument's own layout, none is a copy, and the temporaries stay
+    small (a layout change of the two 1.5 GB caches needs GBs)."""
+    cfg = get_config("smollm-135m").replace(quant=QuantConfig(mode="off"))
+    n, s_max = 64, 2048
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda k: T.init_params(k, cfg), jax.random.PRNGKey(0)))
+    caches = on_chip(jax.eval_shape(lambda: T.init_caches(cfg, n, s_max)))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    compiled = jax.jit(fused_decode_fn(cfg), donate_argnums=(2,)).lower(
+        params, i32(n, 1), caches, i32(n), i32(n), key).compile()
+    text = compiled.as_text()
+
+    stacked = "bf16[" + ",".join(map(str, caches.k.shape)) + "]"
+    layer = "bf16[" + ",".join(map(str, caches.k.shape[1:])) + "]"
+    arg = re.search(re.escape(stacked) + r"\{([^}]*)\} parameter\(\d+\).*op_name=\"caches\.k\"",
+                    text)
+    assert arg, "no caches.k parameter in the program"
+    want = {stacked: arg.group(1), layer: _layer_layout(arg.group(1))}
+    instr = re.compile(r"^\s*(?:ROOT\s+)?(%\S+) = (" + re.escape(stacked) + "|"
+                       + re.escape(layer) + r")\{([^}]*)\} ([\w-]+)\(", re.M)
+    found = instr.findall(text)
+    assert any(op == "dynamic-update-slice" for *_, op in found)
+    bad = [(name, shape, lay, op) for name, shape, lay, op in found
+           if lay != want[shape] or op == "copy"]
+    assert not bad, bad
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
